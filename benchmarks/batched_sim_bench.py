@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.queueing_sim import (generate_stream, generate_streams, simulate,
                                 simulate_fifo_batch)
@@ -107,4 +108,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

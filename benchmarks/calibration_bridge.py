@@ -15,6 +15,7 @@ import pathlib
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ServerParams, Problem, TaskSet, paper_problem, solve
 
 from .common import emit
@@ -86,4 +87,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (ServerParams, Problem, contraction_certificate,
                         paper_problem, safe_step_size, solve_fixed_point,
                         solve_pga, solve_pga_backtracking)
@@ -77,4 +78,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

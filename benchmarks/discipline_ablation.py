@@ -35,6 +35,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.queueing_sim import (DISCIPLINES, generate_stream, simulate,
                                 simulate_batch, sweep_disciplines)
@@ -198,4 +199,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

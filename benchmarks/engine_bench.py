@@ -33,6 +33,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models import init_params, reduced
 from repro.serving import DecodeEngine
@@ -162,4 +163,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_tasks
 from repro.core.calibration import calibrate_taskset
 
@@ -36,4 +37,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

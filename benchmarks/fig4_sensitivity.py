@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.compat import enable_x64
 from repro.core import objective, paper_problem, rounding_lower_bound
 from repro.queueing_sim import sweep
@@ -95,4 +96,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import (ServerParams, Problem, paper_problem, sandwich,
                         solve, solve_fixed_point)
 
@@ -31,4 +32,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -34,6 +34,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.core.mgc import mgc_wait_np
 from repro.queueing_sim import (event_loop_mgc, free_server_numpy,
@@ -245,4 +246,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

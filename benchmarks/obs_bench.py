@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.obs import (MetricsRegistry, StreamingHistogram, Tracer,
                        jax_hooks, validate_request_trees)
@@ -255,4 +256,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

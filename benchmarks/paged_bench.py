@@ -38,6 +38,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.core.batch_service import batch_service_wait, fit_step_latency
 from repro.core.params import paper_tasks
@@ -238,4 +239,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

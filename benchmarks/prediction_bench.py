@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.data.predictor import LengthPredictor
 from repro.queueing_sim import generate_streams, sweep_disciplines
@@ -209,4 +210,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -34,6 +34,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem
 from repro.core.allocator import solve
 from repro.queueing_sim import (Segment, ci95, generate_drift_trace,
@@ -305,4 +306,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

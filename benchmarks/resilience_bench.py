@@ -38,6 +38,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem, retry_fixed_point
 from repro.core.allocator import solve
 from repro.faults import (ArrivalBurst, DroppedCompletions, FaultSet,
@@ -280,4 +281,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -8,6 +8,8 @@ import sys
 import time
 import traceback
 
+from repro import compile_cache
+
 MODULES = (
     "table1",            # Table I: optimal allocations
     "fig2_fit",          # Fig 2: accuracy-curve calibration
@@ -44,4 +46,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import paper_problem, solve_mgc
 from repro.queueing_sim import generate_stream, pk_prediction, sweep
 from repro.serving import LLMServer, ServerConfig
@@ -94,4 +95,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

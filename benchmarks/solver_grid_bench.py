@@ -23,6 +23,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ServerParams, Problem, paper_problem, solve
 from repro.sweeps import solve_grid
 
@@ -142,4 +143,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

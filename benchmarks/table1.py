@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (PAPER_TABLE1_LSTAR, paper_problem, solve,
                         solve_fixed_point, solve_pga_backtracking)
 from repro.sweeps import reference_check, solve_grid
@@ -50,4 +51,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

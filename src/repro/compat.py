@@ -1,10 +1,10 @@
-"""Compatibility helpers papering over JAX API drift.
+"""Version- and backend-sensitive JAX calls, in one place.
 
-``jax.enable_x64`` (the context-manager form) was removed in JAX 0.4.37;
-``jax.experimental.enable_x64`` is the supported spelling on both older and
-newer releases. Everything in the repo that needs double precision for the
-control-plane solvers goes through :func:`enable_x64` so the next rename is
-a one-line fix.
+Written for the installed JAX (0.9). Everything in the repo that needs
+double precision (the control-plane solvers, the queueing DES) goes
+through :func:`enable_x64`, which also keeps that work on the host CPU:
+the TPU only emulates float64, and the allocator's solve came out NaN
+there.
 
 Buffer donation is version- and backend-sensitive too: some backends (and
 older CPU clients) silently ignore ``donate_argnums`` and warn on every
@@ -15,20 +15,23 @@ spam elsewhere.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import warnings
 
 import jax
 
-try:  # pragma: no cover - depends on installed JAX version
-    _enable_x64 = jax.enable_x64  # type: ignore[attr-defined]
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
 
-
+@contextlib.contextmanager
 def enable_x64(enabled: bool = True):
-    """Context manager enabling 64-bit JAX computation within its scope."""
-    return _enable_x64(enabled)
+    """64-bit JAX computation within the scope, on the host CPU device.
+
+    Arrays made and programs run inside land on the CPU whatever the
+    default backend is, so the float64 solvers give the same answer in a
+    process that holds a TPU as in a CPU-only one.
+    """
+    with jax.enable_x64(enabled), jax.default_device(jax.devices("cpu")[0]):
+        yield
 
 
 @functools.lru_cache(maxsize=1)
@@ -75,16 +78,3 @@ def jit(fun, *, donate_argnums=(), label=None, **kwargs):
         return jax.jit(fun, donate_argnums=donate_argnums, **kwargs)
     return jax.jit(fun, **kwargs)
 
-
-def pallas_tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across the CompilerParams rename.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; the 0.4.x line this repo is
-    pinned against only has ``pltpu.TPUCompilerParams``.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

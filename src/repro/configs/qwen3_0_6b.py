@@ -1,4 +1,4 @@
-"""Qwen3-0.6B: dense GQA decoder with qk-norm. [hf:Qwen/Qwen3-8B family]"""
+"""Qwen3-0.6B: dense GQA decoder with qk-norm. [hf:Qwen/Qwen3-0.6B]"""
 from repro.models.config import ModelConfig
 
 
@@ -16,5 +16,5 @@ def config() -> ModelConfig:
         qk_norm=True,
         rope_theta=1_000_000.0,
         tie_embeddings=True,
-        source="hf:Qwen/Qwen3-8B",
+        source="hf:Qwen/Qwen3-0.6B",
     )

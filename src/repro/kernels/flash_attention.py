@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -128,7 +127,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((G * block_q,), jnp.float32),
             pltpu.VMEM((G * block_q,), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

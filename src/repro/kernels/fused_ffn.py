@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK_T = 128
 DEFAULT_BLOCK_F = 512
@@ -76,7 +75,7 @@ def fused_ffn(x, wg, wu, wd, *, block_t: int = DEFAULT_BLOCK_T,
         out_specs=pl.BlockSpec((1, block_t, d), lambda e, i, j: (e, i, 0)),
         out_shape=jax.ShapeDtypeStruct((E, T, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wg, wu, wd)

@@ -1,10 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas kernel runs compiled; on CPU (this
-container, and any unit-test environment) it runs in interpret mode, which
-executes the same kernel body in Python for correctness. ``force_ref=True``
-bypasses Pallas entirely (used by the dry-run so the XLA cost model sees
-analyzable HLO instead of an opaque custom call).
+Dispatch policy (:func:`_interpret`): on the TPU backend the Pallas kernel
+runs compiled; on the CPU backend (unit tests) it runs in interpret mode,
+which executes the same kernel body for correctness; any other backend is
+an error, never a silent interpreter. The choice follows the process's
+default backend, so a compile for a described chip made from a CPU
+process must call the kernels with ``interpret=False`` itself.
+``force_ref=True`` bypasses Pallas entirely (used by the dry-run so the
+XLA cost model sees analyzable HLO instead of an opaque custom call).
 
 Model-facing adapters translate between model layouts ([B, S, nh, hd]) and
 kernel layouts ([Bkv, G, S, hd] etc.).
@@ -26,7 +29,12 @@ Array = jnp.ndarray
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on the TPU or interpreted on the "
+            f"CPU; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 # ------------------------------------------------------------------ attention
@@ -75,25 +83,26 @@ def decode_attention(q: Array, k: Array, v: Array, valid: Array, *,
 
 
 def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
-                           block_tables: Array, pos: Array, *,
+                           block_tables: Array, pos: Array, *, layer: int,
                            force_ref: bool = False) -> Array:
-    """Model layout: q [B,1,nh,hd]; k/v_pool [P,bs,nkv,hd];
-    block_tables [B,n_bt]; pos [B] -> [B,1,nh,hd].
+    """Model layout: q [B,1,nh,hd]; k/v_pool [L,P,nkv,bs,hd] (attends
+    layer ``layer``); block_tables [B,n_bt]; pos [B] -> [B,1,nh,hd].
 
-    ``force_ref`` densifies the pool through the block table (gather +
-    masked reference attend) — the cross-check path for the scalar-prefetch
-    kernel.
+    ``force_ref`` densifies the layer's pool through the block table
+    (gather + masked reference attend) — the cross-check path for the
+    scalar-prefetch kernel.
     """
+    from ..models.attention import gather_paged
+
     B, _, nh, hd = q.shape
-    P, bs, nkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    P, nkv, bs = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
     n_bt = block_tables.shape[1]
     G = nh // nkv
     qk = q.reshape(B, nkv, G, hd)
     if force_ref:
         C = n_bt * bs
-        gather = jnp.clip(block_tables, 0, P - 1)
-        kk = k_pool[gather].reshape(B, C, nkv, hd)
-        vv = v_pool[gather].reshape(B, C, nkv, hd)
+        kk = gather_paged(k_pool[layer], block_tables)     # [B, C, nkv, hd]
+        vv = gather_paged(v_pool[layer], block_tables)
         valid = (jnp.arange(C)[None, :] <= pos[:, None]) \
             & jnp.repeat(block_tables < P, bs, axis=1)
         out = ref.decode_attention_ref(
@@ -102,7 +111,7 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
             vv.transpose(0, 2, 1, 3).reshape(B * nkv, C, hd),
             jnp.repeat(valid[:, None, :], nkv, 1).reshape(B * nkv, C))
         return out.reshape(B, 1, nh, hd)
-    out = _paged_pallas(qk, k_pool, v_pool, block_tables, pos,
+    out = _paged_pallas(qk, k_pool, v_pool, block_tables, pos, layer=layer,
                         interpret=_interpret())
     return out.reshape(B, 1, nh, hd)
 

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..compat import pallas_tpu_compiler_params
 
 DEFAULT_CHUNK = 64
 
@@ -92,7 +91,7 @@ def rwkv6_scan(r, k, v, la, u, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((BH, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, la, u)

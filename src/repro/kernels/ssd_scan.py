@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..compat import pallas_tpu_compiler_params
 
 DEFAULT_CHUNK = 128
 
@@ -101,7 +100,7 @@ def ssd_scan(x, dt, a, Bm, Cm, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((BH, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a, Bm, Cm)

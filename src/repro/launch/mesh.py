@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -26,7 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False,
         axes = ("pod", "data", "model")[-len(shape):]
     n = int(np.prod(shape))
     try:
-        return jax.make_mesh(shape, axes)
+        # the model code leaves propagation to GSPMD (`sharding/partition`
+        # pins inputs and parameters only), so the axes are Auto, not
+        # make_mesh's Explicit default
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     except (ValueError, AssertionError):
         devices = jax.devices()
         if len(devices) < n:

@@ -55,19 +55,21 @@ class PagedKVCache(NamedTuple):
     a shared pool of ``P`` fixed-size blocks and each slot maps its logical
     positions onto pool blocks through a block table:
 
-        k, v          [L, P, bs, nkv, hd]   pool (bs = block_size); the same
+        k, v          [L, P, nkv, bs, hd]   pool (bs = block_size); the same
                                             block id addresses layer-aligned
-                                            physical blocks in every layer
+                                            physical blocks in every layer;
+                                            head-major, so each block is one
+                                            (bs, hd) TPU tile per KV head
         block_tables  [B, n_bt] int32       per-slot logical->physical map;
                                             entries == P (one past the pool)
                                             are the UNASSIGNED sentinel
         length        [L, B] int32          per-layer per-slot position
-        k_scale/v_scale [L, P, bs, nkv] f32 absmax scales when the pool is
+        k_scale/v_scale [L, P, nkv, bs] f32 absmax scales when the pool is
                                             int8 (``kv_cache_dtype="int8"``);
                                             None for full-precision pools
 
     Logical position ``p`` of slot ``b`` lives at
-    ``pool[layer, block_tables[b, p // bs], p % bs]``. Writes through a
+    ``pool[layer, block_tables[b, p // bs], :, p % bs]``. Writes through a
     sentinel entry are dropped (``.at[...].set(mode="drop")``), so rows
     whose requests have retired can keep stepping inside a fused scan
     without corrupting blocks that were freed and reassigned; reads clip
@@ -93,12 +95,12 @@ class PagedKVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def capacity(self) -> int:
         """Per-slot logical capacity (block-table width x block size)."""
-        return self.block_tables.shape[1] * self.k.shape[2]
+        return self.block_tables.shape[1] * self.block_size
 
 
 def _quantize(t: Array):
@@ -222,7 +224,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
     assert cfg.sliding_window is None, "paged KV requires full attention"
     dtype = dtype or cfg.jdtype
     L = cfg.n_layers
-    shape = (L, n_blocks, block_size, cfg.n_kv_eff, cfg.hd)
+    shape = (L, n_blocks, cfg.n_kv_eff, block_size, cfg.hd)
     tables = jnp.full((batch, n_bt), n_blocks, jnp.int32)
     length = jnp.zeros((L, batch), jnp.int32)
     if cfg.kv_cache_dtype == "int8":
@@ -236,6 +238,17 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
                         block_tables=tables, length=length)
 
 
+def gather_paged(pool: Array, block_tables: Array) -> Array:
+    """One layer's pool [P, nkv, bs, ...] -> dense [B, n_bt*bs, nkv, ...]
+    through the block tables [B, n_bt]. Sentinel entries clip to a real
+    block; callers mask by position."""
+    P, bs = pool.shape[0], pool.shape[2]
+    B, n_bt = block_tables.shape
+    g = pool[jnp.clip(block_tables, 0, P - 1)]       # [B, n_bt, nkv, bs, ...]
+    g = jnp.swapaxes(g, 2, 3)                        # [B, n_bt, bs, nkv, ...]
+    return g.reshape((B, n_bt * bs) + g.shape[3:])
+
+
 def attn_decode_paged(cfg: ModelConfig, p: dict, x: Array, pc: PagedKVCache,
                       pos, layer: int):
     """One-token step against the paged block pool.
@@ -243,7 +256,7 @@ def attn_decode_paged(cfg: ModelConfig, p: dict, x: Array, pc: PagedKVCache,
     x [B,1,d]; ``pc`` the layer-stacked :class:`PagedKVCache`; ``pos`` [B]
     the layer's per-slot positions; ``layer`` a static index. Returns
     (y [B,1,d], pc) with the new token's KV scattered into
-    ``pool[layer, block_tables[b, pos//bs], pos % bs]`` — writes through
+    ``pool[layer, block_tables[b, pos//bs], :, pos % bs]`` — writes through
     sentinel / out-of-table positions are dropped, so retired rows riding
     a fused scan are harmless. The attend path gathers the slot's blocks
     back into dense ``[B, C, nkv, hd]`` (reference; pinned token-for-token
@@ -262,35 +275,39 @@ def attn_decode_paged(cfg: ModelConfig, p: dict, x: Array, pc: PagedKVCache,
     blk = jnp.where(bidx < n_bt,
                     pc.block_tables[rows, jnp.minimum(bidx, n_bt - 1)], P)
     off = pos % bs
+
+    heads = jnp.arange(pc.k.shape[2])[None, :]
+
+    def put(pool, val):
+        # one (row, head) vector per update, so the scatter's window is the
+        # minor dim alone and XLA keeps the pool in the kernel's layout
+        return pool.at[layer, blk[:, None], heads, off[:, None]].set(
+            val[:, 0], mode="drop")
+
     quant = pc.k_scale is not None
     if quant:
         kq, ks = _quantize(k_new)
         vq, vs = _quantize(v_new)
-        k_pool = pc.k.at[layer, blk, off].set(kq[:, 0], mode="drop")
-        v_pool = pc.v.at[layer, blk, off].set(vq[:, 0], mode="drop")
-        k_sc = pc.k_scale.at[layer, blk, off].set(ks[:, 0], mode="drop")
-        v_sc = pc.v_scale.at[layer, blk, off].set(vs[:, 0], mode="drop")
-        pc = pc._replace(k=k_pool, v=v_pool, k_scale=k_sc, v_scale=v_sc)
+        pc = pc._replace(k=put(pc.k, kq), v=put(pc.v, vq),
+                         k_scale=put(pc.k_scale, ks),
+                         v_scale=put(pc.v_scale, vs))
     else:
-        k_pool = pc.k.at[layer, blk, off].set(k_new[:, 0], mode="drop")
-        v_pool = pc.v.at[layer, blk, off].set(v_new[:, 0], mode="drop")
-        pc = pc._replace(k=k_pool, v=v_pool)
+        pc = pc._replace(k=put(pc.k, k_new), v=put(pc.v, v_new))
 
     if cfg.use_decode_kernel and not quant:
         from ..kernels import ops as kops
-        out = kops.paged_decode_attention(q, pc.k[layer], pc.v[layer],
-                                          pc.block_tables, pos)
+        out = kops.paged_decode_attention(q, pc.k, pc.v, pc.block_tables,
+                                          pos, layer=layer)
         y = jnp.einsum("bsh,hd->bsd", out.reshape(B, 1, -1), p["wo"])
         return y, pc
     # reference / int8 path: gather the slot's blocks into the dense
     # [B, C, nkv, hd] layout (sentinels clip to a real block; the validity
     # mask hides whatever they alias) and reuse the slot attend
-    gather = jnp.clip(pc.block_tables, 0, P - 1)          # [B, n_bt]
-    k = pc.k[layer][gather].reshape(B, C, cfg.n_kv_eff, cfg.hd)
-    v = pc.v[layer][gather].reshape(B, C, cfg.n_kv_eff, cfg.hd)
+    k = gather_paged(pc.k[layer], pc.block_tables)
+    v = gather_paged(pc.v[layer], pc.block_tables)
     if quant:
-        k_sc = pc.k_scale[layer][gather].reshape(B, C, cfg.n_kv_eff)
-        v_sc = pc.v_scale[layer][gather].reshape(B, C, cfg.n_kv_eff)
+        k_sc = gather_paged(pc.k_scale[layer], pc.block_tables)
+        v_sc = gather_paged(pc.v_scale[layer], pc.block_tables)
         k = _dequantize(k, k_sc, x.dtype)
         v = _dequantize(v, v_sc, x.dtype)
     valid = _decode_valid(cfg, pos, pos, B, C, per_row=True)

@@ -46,8 +46,10 @@ Stochastic sampling (``temperature > 0``) is **chunk-invariant**: token
 identical streams — the per-slot key depends only on the request id and
 the token index, never on batch composition or chunk boundaries.
 
-Padding contract: batched admission right-pads prompts, which is exact for
-attention backbones (causal masking means the last real token's logits are
+Padding contract: batched admission right-pads prompts, to a
+power-of-two multiple of the block so that a few prefill programs serve
+every prompt length (``_prefill_len``); this is exact for attention
+backbones (causal masking means the last real token's logits are
 unchanged, and pad KV slots are overwritten by decode before the per-row
 ``length`` mask can expose them). Recurrent/hybrid backbones and sliding
 windows fold pads into carried state, so there admissions are batched per
@@ -82,6 +84,9 @@ from ..models import decode_step, forward
 from ..models.config import ModelConfig
 
 Array = jnp.ndarray
+
+# smallest prefill width of a dense-slot engine (paged: the block size)
+PREFILL_GRANULE = 16
 
 
 @dataclasses.dataclass
@@ -376,7 +381,7 @@ class ContinuousBatchingEngine:
         ``row_cache`` leaves are [L, k, S, ...] (prefill at capacity = the
         padded prompt length S); ``rows_bt`` [k, n_bt] are the slots' new
         block-table rows (prompt blocks assigned, rest sentinel). Logical
-        position p of row r lands at ``pool[:, rows_bt[r, p // bs],
+        position p of row r lands at ``pool[:, rows_bt[r, p // bs], :,
         p % bs]``; pad positions (p >= lengths[r]) scatter through the
         sentinel and are dropped.
         """
@@ -393,8 +398,14 @@ class ContinuousBatchingEngine:
                         P)                                   # [k, S]
         off = jnp.broadcast_to(ppos % bs, blk.shape)         # [k, S]
 
+        layers = jnp.arange(pc.k.shape[0])[:, None, None, None]
+        heads = jnp.arange(pc.k.shape[2])
+
         def scatter(pool, val):
-            return pool.at[:, blk, off].set(val, mode="drop")
+            # pool [L, P, nkv, bs, ...] <- val [L, k, S, nkv, ...], one
+            # (layer, row, position, head) vector per update
+            return pool.at[layers, blk[None, :, :, None], heads,
+                           off[None, :, :, None]].set(val, mode="drop")
 
         new = {"k": scatter(pc.k, row.k), "v": scatter(pc.v, row.v)}
         if pc.k_scale is not None:
@@ -553,10 +564,27 @@ class ContinuousBatchingEngine:
             self._admit_group(group)
         return flags
 
+    def _prefill_len(self, S: int) -> int:
+        """Prefill width for a group whose longest prompt is ``S``.
+
+        Where right-padding is exact (:meth:`_can_pad_batch`) the width is
+        rounded up to a power-of-two multiple of the block
+        (``block_size``, or :data:`PREFILL_GRANULE` for dense slots),
+        capped at the capacity, so a few prefill programs serve every
+        prompt length; elsewhere a group shares one length and prefills
+        at exactly that.
+        """
+        if not self._can_pad_batch():
+            return S
+        w = self.block_size or PREFILL_GRANULE
+        while w < S:
+            w *= 2
+        return max(S, min(w, self.capacity))
+
     def _admit_group(self, group) -> None:
         lengths = np.asarray([len(req[1]) for _, req in group],
                              dtype=np.int32)
-        S = int(lengths.max())
+        S = self._prefill_len(int(lengths.max()))
         tokens = np.zeros((len(group), S), dtype=np.int32)
         for r, (_, req) in enumerate(group):
             tokens[r, :lengths[r]] = req[1]

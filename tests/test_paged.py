@@ -188,6 +188,27 @@ def test_one_compile_serves_all_budgets(setup, requests):
     jax_hooks.reset()
 
 
+def test_prefill_buckets_bound_compiles(setup):
+    """Sixteen distinct prompt lengths compile the prefill once per bucket
+    (power-of-two multiples of the block, up to the capacity), never once
+    per length."""
+    cfg, params = setup
+    jax_hooks.reset()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2, capacity=128,
+                                   chunk=4, paged=True, block_size=8)
+    lengths = [2 + 7 * i for i in range(16)]            # 2 .. 107
+    buckets = {eng._prefill_len(n) for n in lengths}
+    assert buckets == {8, 16, 32, 64, 128}
+    for rid, n in enumerate(lengths):
+        assert eng.admit(rid, np.arange(1, n + 1, dtype=np.int32) % 97,
+                         budget=2, max_extra=1)
+        while eng.n_active:
+            eng.step_chunk()
+    assert jax_hooks.assert_max_compiles("continuous.prefill",
+                                         len(buckets)) == len(buckets)
+    jax_hooks.reset()
+
+
 def test_occupancy_gauges(setup, requests):
     cfg, params = setup
     eng = ContinuousBatchingEngine(cfg, params, max_slots=4, capacity=64,
@@ -273,7 +294,7 @@ def test_init_paged_cache_shapes(setup):
     cfg, _ = setup
     pc = init_paged_cache(cfg, batch=3, n_blocks=10, block_size=4, n_bt=6)
     assert isinstance(pc, PagedKVCache)
-    assert pc.k.shape[:3] == (cfg.n_layers, 10, 4)
+    assert pc.k.shape == (cfg.n_layers, 10, cfg.n_kv_eff, 4, cfg.hd)
     assert pc.block_tables.shape == (3, 6)
     assert bool((pc.block_tables == 10).all())      # all-sentinel at init
     assert pc.n_blocks == 10 and pc.block_size == 4 and pc.capacity == 24
