@@ -69,11 +69,14 @@ def jit(fun, *, donate_argnums=(), label=None, **kwargs):
     label's counter, making retraces observable and assertable
     (``obs.jax_hooks.assert_max_compiles``). Per-call cost after tracing
     is zero — jit caches the traced computation, the wrapper only runs
-    while tracing.
+    while tracing. The label is also the wrapper's ``__name__``, so the
+    XLA module, and its line in a device trace, is ``jit_<label>``
+    whatever the python function is called.
     """
     if label is not None:
         from .obs import jax_hooks
         fun = jax_hooks.count_traces(fun, label)
+        fun.__name__ = fun.__qualname__ = label
     if donate_argnums and donation_supported():
         return jax.jit(fun, donate_argnums=donate_argnums, **kwargs)
     return jax.jit(fun, **kwargs)

@@ -9,7 +9,9 @@ path overhead (<3% decode fast path, <10% DES) and the histogram's
 percentile error bound against ``numpy.percentile``.
 
 - :mod:`~repro.obs.trace` — per-request span recording + Chrome
-  trace-event / Perfetto JSON export, and the shared monotonic
+  trace-event / Perfetto JSON export (wall events also enter
+  ``jax.profiler.TraceAnnotation``, so they land on the device trace's
+  clock while a profile runs), and the shared monotonic
   :func:`~repro.obs.trace.timecall` timing helper.
 - :mod:`~repro.obs.metrics` — counters, gauges, log-bucketed streaming
   histograms (exact-bound percentiles, mergeable snapshots).

@@ -13,6 +13,18 @@ processes:
   chunks, controller re-solves), recorded by :meth:`Tracer.span` around
   real work.
 
+One span, two sinks: every event stamped on the wall clock
+(:meth:`Tracer.span`, and :meth:`Tracer.counter` / :meth:`Tracer.instant`
+with ``ts_s=None``) also enters ``jax.profiler.TraceAnnotation`` under its
+name, with its args (a counter's values) as stats. While a profile runs
+(``jax.profiler.start_trace``), the event therefore lands in the
+profiler's host plane on the device trace's clock, where a reduction can
+set it against what the device did; with no profile running it costs
+about a microsecond. Virtual-clock events (:meth:`Tracer.complete`, an
+explicit ``ts_s``) lie on another timeline and stay in the Chrome JSON
+only. ``jax.profiler`` is imported on a tracer's first wall event, so this
+module imports without JAX.
+
 Every event that belongs to a request carries ``args={"rid": ...}`` so the
 span tree can be validated programmatically (:func:`spans_by_request`,
 :func:`validate_request_trees`) — the acceptance contract is that a replay
@@ -24,7 +36,7 @@ Disabled-path cost contract: producers hold ``tracer=None`` (or
 single ``is not None`` / ``tracer.enabled`` check, so a run without
 observability pays one pointer comparison per would-be event and allocates
 nothing. :class:`NullTracer` additionally makes every method a no-op so
-unconditional call sites stay safe.
+unconditional call sites stay safe; it never annotates the profiler.
 
 This module also owns the ONE wall-clock timing helper
 (:func:`timecall`) shared by ``serving.server.LLMServer`` and
@@ -37,7 +49,7 @@ from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "VIRTUAL_PID", "WALL_PID",
            "monotonic", "timecall", "spans_by_request",
@@ -53,6 +65,16 @@ _PID_NAMES = {VIRTUAL_PID: "queueing timeline (virtual clock)",
 def monotonic() -> float:
     """The repo's single monotonic wall clock (seconds)."""
     return time.perf_counter()
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or a no-op stand-in where JAX is
+    not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return lambda name, **args: nullcontext()
+    return TraceAnnotation
 
 
 def timecall(fn, *args, warmup: int = 0, **kwargs):
@@ -79,7 +101,8 @@ class Tracer:
     Virtual-timeline producers pass explicit ``ts_s`` (seconds on the
     simulated clock); wall producers use the :meth:`span` context manager
     (monotonic clock anchored at tracer construction). Timestamps are
-    stored in microseconds, the trace-event unit.
+    stored in microseconds, the trace-event unit. Wall events also enter
+    the profiler's host plane (module docstring).
     """
 
     enabled = True
@@ -88,6 +111,7 @@ class Tracer:
         self._events: list = []
         self._wall0 = monotonic()
         self._named_pids: set = set()
+        self._annotation = None     # jax.profiler.TraceAnnotation, lazily
 
     # ------------------------------------------------------------- plumbing
     def __len__(self) -> int:
@@ -107,6 +131,12 @@ class Tracer:
         self._name_pid(ev.get("pid", VIRTUAL_PID))
         self._events.append(ev)
 
+    def _annotate(self, name: str, args):
+        """The profiler's host span for a wall event, its args as stats."""
+        if self._annotation is None:
+            self._annotation = _profiler_annotation()
+        return self._annotation(name, **(args or {}))
+
     # ------------------------------------------------------------ recording
     def complete(self, name: str, ts_s: float, dur_s: float, *, tid: int = 0,
                  pid: int = VIRTUAL_PID, cat: str = "", args=None) -> None:
@@ -121,7 +151,11 @@ class Tracer:
 
     def instant(self, name: str, ts_s: float | None = None, *, tid: int = 0,
                 pid: int = VIRTUAL_PID, cat: str = "", args=None) -> None:
-        """An instant ("i") event; ``ts_s=None`` stamps the wall clock."""
+        """An instant ("i") event; ``ts_s=None`` stamps the wall clock
+        (and the profiler's host plane)."""
+        if ts_s is None:
+            with self._annotate(name, args):
+                pass
         ts = self._wall_us() if ts_s is None else ts_s * 1e6
         ev = {"ph": "i", "name": name, "pid": pid, "tid": tid, "ts": ts,
               "s": "t"}
@@ -133,7 +167,12 @@ class Tracer:
 
     def counter(self, name: str, ts_s: float | None = None, *, tid: int = 0,
                 pid: int = VIRTUAL_PID, **values) -> None:
-        """A counter ("C") sample rendered as a stacked track."""
+        """A counter ("C") sample rendered as a stacked track; ``ts_s=None``
+        stamps the wall clock (and the profiler's host plane, with the
+        values as stats)."""
+        if ts_s is None:
+            with self._annotate(name, values):
+                pass
         ts = self._wall_us() if ts_s is None else ts_s * 1e6
         self._push({"ph": "C", "name": name, "pid": pid, "tid": tid,
                     "ts": ts, "args": {k: float(v)
@@ -142,10 +181,12 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, tid: int = 0, pid: int = WALL_PID,
              cat: str = "", args=None):
-        """Wall-clock span around real work (engine dispatch, re-solve)."""
+        """Wall-clock span around real work (engine dispatch, re-solve),
+        also a span of the profiler's host plane."""
         t0 = self._wall_us()
         try:
-            yield self
+            with self._annotate(name, args):
+                yield self
         finally:
             ev = {"ph": "X", "name": name, "pid": pid, "tid": tid,
                   "ts": t0, "dur": self._wall_us() - t0}

@@ -82,6 +82,7 @@ import numpy as np
 from .. import compat
 from ..models import decode_step, forward
 from ..models.config import ModelConfig
+from ..obs.trace import WALL_PID
 
 Array = jnp.ndarray
 
@@ -209,9 +210,10 @@ class ContinuousBatchingEngine:
         self.temperature = float(temperature)
         self.seed = int(seed)
         self._base_key = None    # built lazily; greedy never touches PRNG
-        # optional wall-span tracing of admission/decode dispatches; one
-        # `is not None` check per dispatch when disabled. Jit labels feed
-        # the obs.jax_hooks compile counters (per compile, not per call).
+        # optional wall-span tracing of admission and decode (``_span``);
+        # one `is not None` check per site when disabled. Jit labels feed
+        # the obs.jax_hooks compile counters (per compile, not per call)
+        # and name the XLA modules (``jit_continuous.scan``, ...).
         self.tracer = tracer
         # optional repro.faults injector bank: its on_decode_step hook
         # fires at every step/chunk boundary (even while idle, so a
@@ -265,6 +267,13 @@ class ContinuousBatchingEngine:
                                   label="continuous.sample")
 
     # ------------------------------------------------------------ internals
+    def _span(self, name: str, **args):
+        """A wall span on the tracer (and the profiler's host plane), or a
+        no-op without one."""
+        if self.tracer is None:
+            return nullcontext()
+        return self.tracer.span(name, cat="engine", args=args)
+
     def _can_page(self) -> bool:
         """Paged decode covers the full-attention backbones: per-position
         KV with causal masking (blocks are position-addressed). Ring
@@ -582,51 +591,56 @@ class ContinuousBatchingEngine:
         return max(S, min(w, self.capacity))
 
     def _admit_group(self, group) -> None:
+        """Prefill ``group`` in one dispatch, insert its rows, and wait for
+        the first tokens. Traced as ``continuous.admit`` with the children
+        ``continuous.blocks`` (paged), ``.prefill``, ``.insert`` and
+        ``.first_sync``."""
+        slots = [slot for slot, _ in group]
         lengths = np.asarray([len(req[1]) for _, req in group],
                              dtype=np.int32)
         S = self._prefill_len(int(lengths.max()))
-        tokens = np.zeros((len(group), S), dtype=np.int32)
-        for r, (_, req) in enumerate(group):
-            tokens[r, :lengths[r]] = req[1]
-        sampling = self.temperature > 0.0
-        keys = (np.stack([self._slot_key(req[0]) for _, req in group])
-                if sampling else None)
-        ctx = (self.tracer.span("continuous.admit", cat="engine",
-                                args={"rows": len(group), "S": S})
-               if self.tracer is not None else nullcontext())
-        with ctx:
-            slot_idx = jnp.asarray([slot for slot, _ in group], jnp.int32)
+        with self._span("continuous.admit", rows=len(group), S=S,
+                        rids=[int(req[0]) for _, req in group]):
+            tokens = np.zeros((len(group), S), dtype=np.int32)
+            for r, (_, req) in enumerate(group):
+                tokens[r, :lengths[r]] = req[1]
+            sampling = self.temperature > 0.0
+            keys = (np.stack([self._slot_key(req[0]) for _, req in group])
+                    if sampling else None)
             if self.paged:
                 # assign the prompt's blocks up front so the insert
                 # scatter lands on real blocks
-                for slot, (_, prompt, _, _) in group:
-                    self._grow_slot_blocks(slot, len(prompt))
-                self._sync_tables()
+                with self._span("continuous.blocks"):
+                    for slot, (_, prompt, _, _) in group:
+                        self._grow_slot_blocks(slot, len(prompt))
+                    self._sync_tables()
+            with self._span("continuous.prefill"):
                 firsts, last, row_cache = self._prefill(
                     self.params, jnp.asarray(tokens), jnp.asarray(lengths),
-                    capacity=S)
-                rows_bt = jnp.asarray(
-                    self._tables_host[[slot for slot, _ in group]])
-                self.cache = self._insert_paged(
-                    row_cache, self.cache, slot_idx, jnp.asarray(lengths),
-                    rows_bt)
-            else:
-                firsts, last, row_cache = self._prefill(
-                    self.params, jnp.asarray(tokens), jnp.asarray(lengths),
-                    capacity=self.capacity)
-                self.cache = self._insert(row_cache, self.cache, slot_idx,
-                                          jnp.asarray(lengths))
-            if sampling:    # first token is emission index g = 0
-                firsts = self._sample(last, jnp.asarray(keys),
-                                      jnp.zeros(len(group), jnp.int32))
-        firsts = np.asarray(firsts)
-        for r, (slot, (rid, prompt, budget, max_extra)) in enumerate(group):
-            first = int(firsts[r])
-            self.slots[slot] = Slot(
-                rid=rid, budget=budget, max_extra=max_extra, generated=1,
-                tokens=[first], last_token=first,
-                prompt_len=int(lengths[r]),
-                key=(keys[r] if sampling else None))
+                    capacity=S if self.paged else self.capacity)
+            with self._span("continuous.insert"):
+                slot_idx = jnp.asarray(slots, jnp.int32)
+                if self.paged:
+                    self.cache = self._insert_paged(
+                        row_cache, self.cache, slot_idx,
+                        jnp.asarray(lengths),
+                        jnp.asarray(self._tables_host[slots]))
+                else:
+                    self.cache = self._insert(row_cache, self.cache,
+                                              slot_idx, jnp.asarray(lengths))
+            with self._span("continuous.first_sync"):
+                if sampling:    # first token is emission index g = 0
+                    firsts = self._sample(last, jnp.asarray(keys),
+                                          jnp.zeros(len(group), jnp.int32))
+                firsts = np.asarray(firsts)
+            for r, (slot, (rid, prompt, budget, max_extra)) in enumerate(
+                    group):
+                first = int(firsts[r])
+                self.slots[slot] = Slot(
+                    rid=rid, budget=budget, max_extra=max_extra,
+                    generated=1, tokens=[first], last_token=first,
+                    prompt_len=int(lengths[r]),
+                    key=(keys[r] if sampling else None))
 
     @property
     def n_active(self) -> int:
@@ -723,36 +737,45 @@ class ContinuousBatchingEngine:
         chunk = self.chunk if chunk is None else chunk
         if self.n_active == 0 or chunk <= 0:
             return []
-        if self.paged:
-            self._ensure_blocks(chunk)
-            self._sync_tables()
-        token = jnp.asarray([s.last_token if s else 0 for s in self.slots],
-                            jnp.int32)
-        alive = jnp.asarray([s is not None for s in self.slots])
-        remaining = jnp.asarray(
-            [s.budget + s.max_extra - s.generated if s else 0
-             for s in self.slots], jnp.int32)
-        keys, gidx = self._keys_gidx()
-        ctx = (self.tracer.span("continuous.decode_chunk", cat="engine",
-                                args={"chunk": chunk,
-                                      "occupancy": self.n_active,
-                                      "tokens_in_use": self.tokens_in_use})
-               if self.tracer is not None else nullcontext())
-        with ctx:
-            toks, self.cache = self._scan(self.params, token, self.cache,
-                                          alive, remaining, keys, gidx,
-                                          chunk=chunk)
-            toks = np.asarray(toks)                  # [chunk, S]
-        finished = []
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            n_take = min(chunk, s.budget + s.max_extra - s.generated)
-            if n_take > 0:
-                s.tokens.extend(int(t) for t in toks[:n_take, i])
-                s.generated += n_take
-                s.last_token = int(toks[n_take - 1, i])
-            if s.generated >= s.budget + s.max_extra:
-                finished.append(s)
-                self._retire_slot(i)
+        # traced as continuous.decode_chunk, tiled in order by its children
+        # prep, dispatch, sync and unpack
+        with self._span("continuous.decode_chunk", chunk=chunk,
+                        occupancy=self.n_active):
+            with self._span("continuous.prep"):
+                if self.paged:
+                    self._ensure_blocks(chunk)
+                    self._sync_tables()
+                token = jnp.asarray([s.last_token if s else 0
+                                     for s in self.slots], jnp.int32)
+                alive = jnp.asarray([s is not None for s in self.slots])
+                remaining = jnp.asarray(
+                    [s.budget + s.max_extra - s.generated if s else 0
+                     for s in self.slots], jnp.int32)
+                keys, gidx = self._keys_gidx()
+                if self.paged and self.tracer is not None:
+                    # KV the admission gate holds against KV the rows use
+                    self.tracer.counter(
+                        "continuous.kv", pid=WALL_PID,
+                        reserved_tokens=(self.allocator.reserved
+                                         * self.block_size),
+                        tokens_in_use=self.tokens_in_use)
+            with self._span("continuous.dispatch"):
+                toks, self.cache = self._scan(self.params, token, self.cache,
+                                              alive, remaining, keys, gidx,
+                                              chunk=chunk)
+            with self._span("continuous.sync"):
+                toks = np.asarray(toks)              # [chunk, S]
+            with self._span("continuous.unpack"):
+                finished = []
+                for i, s in enumerate(self.slots):
+                    if s is None:
+                        continue
+                    n_take = min(chunk, s.budget + s.max_extra - s.generated)
+                    if n_take > 0:
+                        s.tokens.extend(int(t) for t in toks[:n_take, i])
+                        s.generated += n_take
+                        s.last_token = int(toks[n_take - 1, i])
+                    if s.generated >= s.budget + s.max_extra:
+                        finished.append(s)
+                        self._retire_slot(i)
         return finished

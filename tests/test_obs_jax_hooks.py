@@ -28,6 +28,22 @@ def _clean_counters():
     jax_hooks.reset()
 
 
+def test_label_names_the_xla_module():
+    """The label, not the python function's name, names the compiled
+    module, so a device trace's module line survives a rename."""
+    import jax.numpy as jnp
+
+    def _private_impl(x, *, k):
+        return x * k
+
+    f = compat.jit(_private_impl, static_argnames=("k",),
+                   label="hooks.scale")
+    text = f.lower(jnp.ones(4), k=3).as_text()
+    assert "module @jit_hooks.scale" in text
+    assert "_private_impl" not in text
+    assert f(jnp.ones(4), k=3).tolist() == [3.0] * 4
+
+
 def test_count_traces_one_per_compile():
     import jax.numpy as jnp
 

@@ -14,8 +14,13 @@ Pins the ``obs.trace`` contracts:
 * an instrumented ``LLMServer`` run exports one validated tree per
   completed request, and the ``ServingReport`` percentile fields agree
   with ``np.percentile`` on the report's own samples;
-* ``NullTracer`` records nothing.
+* ``NullTracer`` records nothing;
+* wall events (spans, and counters and instants stamped on the wall
+  clock) also land in the profiler's host plane under their names, with
+  their args as stats, while virtual-clock events, ``NullTracer`` and the
+  Chrome export are untouched by a running profile.
 """
+import glob
 import json
 
 import numpy as np
@@ -159,3 +164,71 @@ def test_server_run_exports_validated_trees(prob):
             float(np.percentile(waits, q, method="inverted_cdf")))
     assert set(rep.system_time_percentiles) == {"p50", "p90", "p99",
                                                 "p99_9"}
+
+
+# ------------------------------------------------ the profiler's host plane
+
+def _record(tr):
+    with tr.span("obs_test.span", args={"rows": 3}):
+        tr.counter("obs_test.kv", pid=WALL_PID, reserved_tokens=96,
+                   tokens_in_use=40)
+    tr.instant("obs_test.mark", args={"rid": 7})
+    tr.complete("obs_test.virtual", ts_s=1.0, dur_s=0.5)
+    tr.counter("obs_test.virtual_counter", ts_s=1.0, depth=2)
+
+
+def _profiled_host_events(tmp_path, record) -> dict:
+    """Run ``record()`` under ``jax.profiler`` and return the events of
+    the profile's ``/host:`` planes: ``{name: [(start_ns, dur_ns,
+    {stat: value})]}``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        record()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_wall_events_land_in_the_profilers_host_plane(tmp_path):
+    host = _profiled_host_events(tmp_path, lambda: _record(Tracer()))
+    (s0, d0, span_stats), = host["obs_test.span"]
+    (s1, d1, kv_stats), = host["obs_test.kv"]
+    (_, _, mark_stats), = host["obs_test.mark"]
+    assert span_stats == {"rows": 3}
+    assert kv_stats == {"reserved_tokens": 96, "tokens_in_use": 40}
+    assert mark_stats == {"rid": 7}
+    assert s0 <= s1 and s1 + d1 <= s0 + d0      # the counter in the span
+    # virtual-clock events lie on another timeline: not annotated
+    assert "obs_test.virtual" not in host
+    assert "obs_test.virtual_counter" not in host
+
+
+def test_null_tracer_adds_nothing_to_the_profile(tmp_path):
+    host = _profiled_host_events(tmp_path, lambda: _record(NULL_TRACER))
+    assert not [name for name in host if name.startswith("obs_test.")]
+
+
+def test_chrome_export_unchanged_by_a_running_profile(tmp_path):
+    def events(tr):      # wall timestamps differ from run to run
+        return [{k: v for k, v in ev.items() if k not in ("ts", "dur")}
+                for ev in tr.to_chrome()["traceEvents"]]
+
+    plain, profiled = Tracer(), Tracer()
+    _record(plain)
+    _profiled_host_events(tmp_path, lambda: _record(profiled))
+    assert events(profiled) == events(plain)
+    kv = next(ev for ev in profiled.to_chrome()["traceEvents"]
+              if ev["name"] == "obs_test.kv")
+    assert kv["ph"] == "C" and kv["pid"] == WALL_PID
+    assert kv["args"] == {"reserved_tokens": 96.0, "tokens_in_use": 40.0}

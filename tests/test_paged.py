@@ -12,7 +12,11 @@ Contracts under test:
 * the paged entry points compile once and serve every budget / block
   layout as data (``obs.jax_hooks`` compile counters),
 * randomized churn preserves the allocator invariants (no double
-  allocation, reservation accounting, full recovery after drain).
+  allocation, reservation accounting, full recovery after drain),
+* with a ``Tracer`` attached the engine records its admission and decode
+  spans (each chunk tiled by prep, dispatch, sync and unpack) and a
+  ``continuous.kv`` counter of KV reserved against KV in use, and serves
+  the same tokens as without one.
 """
 import dataclasses
 
@@ -24,7 +28,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import init_params, reduced
 from repro.models.attention import PagedKVCache, init_paged_cache
-from repro.obs import jax_hooks
+from repro.obs import Tracer, jax_hooks
 from repro.serving.continuous import BlockAllocator, ContinuousBatchingEngine
 
 try:
@@ -221,6 +225,89 @@ def test_occupancy_gauges(setup, requests):
     while eng.n_active:
         eng.step_chunk()
     assert eng.tokens_in_use == 0 and eng.blocks_in_use == 0
+
+
+# ------------------------------------------------------------ engine spans
+CHUNK_PARTS = ("continuous.prep", "continuous.dispatch", "continuous.sync",
+               "continuous.unpack")
+ADMIT_PARTS = ("continuous.blocks", "continuous.prefill",
+               "continuous.insert", "continuous.first_sync")
+
+
+def _parts(spans, parent, names):
+    """The spans named in ``names`` that lie inside ``parent``, by start."""
+    end = parent["ts"] + parent["dur"]
+    return sorted((e for e in spans if e["name"] in names
+                   and parent["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end),
+                  key=lambda e: e["ts"])
+
+
+def test_engine_spans_and_kv_counter(setup, requests):
+    cfg, params = setup
+    jax_hooks.reset()
+    tr = Tracer()
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=4, capacity=64,
+                                   chunk=5, paged=True, block_size=8,
+                                   tracer=tr)
+    kv_expected, pending, done = [], list(requests), {}
+    while pending or eng.n_active:
+        if pending:
+            flags = eng.admit_many(pending)
+            pending = [r for r, ok in zip(pending, flags) if not ok]
+        kv_expected.append((float(eng.allocator.reserved * eng.block_size),
+                            float(eng.tokens_in_use)))
+        for s in eng.step_chunk():
+            done[s.rid] = s.tokens
+    events = tr.to_chrome()["traceEvents"]
+    spans = [e for e in events if e["ph"] == "X"]
+
+    # every chunk is tiled, in order, by its four parts
+    chunks = [e for e in spans if e["name"] == "continuous.decode_chunk"]
+    assert len(chunks) == len(kv_expected)
+    for c in chunks:
+        parts = _parts(spans, c, CHUNK_PARTS)
+        assert [e["name"] for e in parts] == list(CHUNK_PARTS)
+        edges = [c["ts"]] + [x for e in parts
+                             for x in (e["ts"], e["ts"] + e["dur"])]
+        edges.append(c["ts"] + c["dur"])
+        gaps = [edges[i + 1] - edges[i] for i in range(0, len(edges), 2)]
+        assert all(0 <= g < 1000 for g in gaps), gaps      # microseconds
+
+    # every admission holds its four parts in order and names its rows
+    admits = [e for e in spans if e["name"] == "continuous.admit"]
+    assert sorted(r for a in admits for r in a["args"]["rids"]) == \
+        sorted(rid for rid, *_ in requests)
+    for a in admits:
+        parts = _parts(spans, a, ADMIT_PARTS)
+        assert [e["name"] for e in parts] == list(ADMIT_PARTS)
+        assert all(p["ts"] + p["dur"] <= q["ts"]
+                   for p, q in zip(parts, parts[1:]))
+        assert a["args"]["rows"] == len(a["args"]["rids"])
+
+    # one KV sample per chunk, inside its prep, equal to the engine's
+    # reservation and occupancy as the chunk began
+    kv = [e for e in events if e["ph"] == "C" and e["name"] == "continuous.kv"]
+    assert [(e["args"]["reserved_tokens"], e["args"]["tokens_in_use"])
+            for e in kv] == kv_expected
+    assert all(r >= u > 0 for r, u in kv_expected)
+    for sample, c in zip(kv, chunks):
+        prep = _parts(spans, c, CHUNK_PARTS)[0]
+        assert prep["ts"] <= sample["ts"] <= prep["ts"] + prep["dur"]
+
+    # each entry point compiled once per shape it served, tracer on
+    shapes = {(a["args"]["rows"], a["args"]["S"]) for a in admits}
+    bounds = {"continuous.scan": 1, "continuous.prefill": len(shapes),
+              "continuous.insert_paged": len(shapes)}
+    counts = jax_hooks.trace_counts()
+    assert set(counts) == set(bounds)
+    for label, n in counts.items():
+        jax_hooks.assert_max_compiles(label, bounds[label])
+    jax_hooks.reset()
+
+    # the tracer changes no token
+    plain = ContinuousBatchingEngine(cfg, params, max_slots=4, capacity=64,
+                                     chunk=5, paged=True, block_size=8)
+    assert done == drain(plain, requests)
 
 
 # ------------------------------------------------------------ allocator unit
