@@ -45,6 +45,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 # Qwen3-0.6B attention widths, and the serving engine's paging
 B, NKV, G, HD = 8, 8, 2, 128
 BLOCK, CAPACITY = 16, 1024
+# the long-reasoning cell's table: 4240 tokens of 16
+LONG_BT = 265
 # bf16 inputs, float32 accumulation: both sides round the softmax weights
 # to bf16 once, at different points
 TOL = dict(rtol=2e-2, atol=2e-2)
@@ -75,31 +77,51 @@ def _close(name: str, got, want) -> None:
     np.testing.assert_allclose(got, want, err_msg=name, **TOL)
 
 
+def paged_case(rng, key, q, name: str, n_bt: int, retired: int = -1,
+               past: int = -1) -> None:
+    """The compiled paged kernel against the gather path of ``ops`` on
+    layer 1 of a shuffled two-layer pool: rows filled to random lengths,
+    the unused tail of every table on the sentinel. Row ``retired`` has
+    an all-sentinel table (no reference: it must come out zero); row
+    ``past`` steps past its 2 blocks, and attends only those."""
+    from repro.kernels import ops
+
+    n_pool = B * n_bt
+    cap = n_bt * BLOCK
+    pos = rng.integers(0, cap, size=B).astype(np.int32)
+    pos[0] = 0
+    n_blocks = pos // BLOCK + 1
+    if past >= 0:
+        pos[past], n_blocks[past] = 5 * BLOCK + 3, 2
+    if retired >= 0:
+        n_blocks[retired] = 0
+    tables = rng.permutation(n_pool).reshape(B, n_bt).astype(np.int32)
+    tables[np.arange(n_bt)[None, :] >= n_blocks[:, None]] = n_pool
+    k_pool, v_pool = (
+        jax.random.normal(k, (2, n_pool, NKV, BLOCK, HD), jnp.float32)
+        .astype(jnp.bfloat16) for k in jax.random.split(key))
+    args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
+    got = np.asarray(ops.paged_decode_attention(*args, layer=1), np.float32)
+    want = ops.paged_decode_attention(*args, layer=1, force_ref=True)
+    live = n_blocks > 0
+    _close(name, got[live], np.asarray(want)[live])
+    assert not np.any(got[~live]), f"{name}: a retired row is not zero"
+
+
 def check_kernels(seed: int) -> None:
     """Compiled decode kernels against their references, bf16."""
     from repro.kernels import ops
 
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    n_bt = CAPACITY // BLOCK
-    n_pool = B * n_bt
 
     def normal(k, shape):
         return jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
 
-    # paged: two layers of a shuffled pool, rows filled to random lengths,
-    # the unused tail of every table on the sentinel
-    pos = rng.integers(0, CAPACITY, size=B).astype(np.int32)
-    pos[0] = 0
-    tables = rng.permutation(n_pool).reshape(B, n_bt).astype(np.int32)
-    tables[np.arange(n_bt)[None, :] > (pos // BLOCK)[:, None]] = n_pool
     q = normal(ks[0], (B, 1, NKV * G, HD))
-    k_pool = normal(ks[1], (2, n_pool, NKV, BLOCK, HD))
-    v_pool = normal(ks[2], (2, n_pool, NKV, BLOCK, HD))
-    args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
-    _close("paged_decode_attention",
-           ops.paged_decode_attention(*args, layer=1),
-           ops.paged_decode_attention(*args, layer=1, force_ref=True))
+    paged_case(rng, ks[1], q, "paged_decode_attention", CAPACITY // BLOCK)
+    paged_case(rng, ks[2], q, "paged_decode_attention.n_bt265", LONG_BT,
+               retired=2, past=3)
 
     # slot: ragged valid lengths over a 4096-token cache; the reference
     # is ref.decode_attention_ref on the kernel's own layout

@@ -753,12 +753,18 @@ class ContinuousBatchingEngine:
                      for s in self.slots], jnp.int32)
                 keys, gidx = self._keys_gidx()
                 if self.paged and self.tracer is not None:
-                    # KV the admission gate holds against KV the rows use
+                    # KV the admission gate holds against KV the rows use;
+                    # blocks the paged kernel streams (live rows' held
+                    # blocks) against the tables' capacity
                     self.tracer.counter(
                         "continuous.kv", pid=WALL_PID,
                         reserved_tokens=(self.allocator.reserved
                                          * self.block_size),
-                        tokens_in_use=self.tokens_in_use)
+                        tokens_in_use=self.tokens_in_use,
+                        streamed_blocks=sum(
+                            -(-s.cache_len // self.block_size)
+                            for s in self.slots if s is not None),
+                        table_blocks=self.max_slots * self.n_bt)
             with self._span("continuous.dispatch"):
                 toks, self.cache = self._scan(self.params, token, self.cache,
                                               alive, remaining, keys, gidx,

@@ -100,6 +100,63 @@ def test_decode_attention_ring_mask():
                                rtol=2e-5, atol=2e-5)
 
 
+# ------------------------------------------------------ paged decode attention
+@pytest.mark.parametrize("G", [2, 4])
+@pytest.mark.parametrize("n_bt", [32, 17])       # 17: not a multiple of ppg
+def test_paged_decode_attention_live_blocks(G, n_bt):
+    """The paged kernel (interpreted) against the gather reference of
+    ``ops`` on layer 1 of a shuffled two-layer pool, 8 pages a group.
+
+    Rows: pos 0, bs-1, bs, mid-table, capacity-1, a retired row (all
+    sentinel), a row stepping past its 3 blocks, a random one. The
+    retired row has no reference (every position masked): it must come
+    out zero. A second call with other positions and tables compiles
+    nothing new.
+    """
+    from repro.kernels import ops
+    from repro.kernels.decode_attention import (pages_per_group,
+                                                paged_decode_attention)
+
+    B, NKV, hd, bs = 8, 8, 128, 16
+    cap, P = n_bt * bs, 8 * n_bt
+    assert pages_per_group(NKV, bs, hd, 2, n_bt) == 8
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = _rand(ks[0], (B, 1, NKV * G, hd), jnp.bfloat16)
+    k_pool = _rand(ks[1], (2, P, NKV, bs, hd), jnp.bfloat16)
+    v_pool = _rand(ks[2], (2, P, NKV, bs, hd), jnp.bfloat16)
+    rng = np.random.default_rng(n_bt * G)
+
+    def case(pos, n_blocks):
+        tables = rng.permutation(P).reshape(B, n_bt).astype(np.int32)
+        tables[np.arange(n_bt)[None, :] >= n_blocks[:, None]] = P
+        args = (q, k_pool, v_pool, jnp.asarray(tables),
+                jnp.asarray(pos, jnp.int32))
+        got = np.asarray(ops.paged_decode_attention(*args, layer=1),
+                         np.float32)
+        want = np.asarray(ops.paged_decode_attention(*args, layer=1,
+                                                     force_ref=True),
+                          np.float32)
+        assert np.all(np.isfinite(got))
+        live = n_blocks > 0
+        np.testing.assert_allclose(got[live], want[live],
+                                   **TOL[jnp.bfloat16])
+        assert not np.any(got[~live])
+
+    pos = np.array([0, bs - 1, bs, cap // 2 + 5, cap - 1, 37, 5 * bs + 3,
+                    int(rng.integers(0, cap))])
+    n_blocks = np.minimum(pos // bs + 1, n_bt)
+    n_blocks[5], n_blocks[6] = 0, 3
+    case(pos, n_blocks)
+    compiled = paged_decode_attention._cache_size()
+
+    pos = np.array([3, bs + 1, 2 * bs - 1, cap - 1, cap // 3, 0, 2 * bs,
+                    int(rng.integers(0, cap))])
+    n_blocks = np.minimum(pos // bs + 1, n_bt)
+    n_blocks[1], n_blocks[5], n_blocks[6] = 0, 0, 1
+    case(pos, n_blocks)
+    assert paged_decode_attention._cache_size() == compiled
+
+
 # -------------------------------------------------------------------- SSD scan
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("BH,S,hd,ds,chunk", [

@@ -15,7 +15,8 @@ Contracts under test:
   allocation, reservation accounting, full recovery after drain),
 * with a ``Tracer`` attached the engine records its admission and decode
   spans (each chunk tiled by prep, dispatch, sync and unpack) and a
-  ``continuous.kv`` counter of KV reserved against KV in use, and serves
+  ``continuous.kv`` counter of KV reserved against KV in use and of the
+  blocks the paged kernel streams against the tables' capacity, and serves
   the same tokens as without one.
 """
 import dataclasses
@@ -254,8 +255,11 @@ def test_engine_spans_and_kv_counter(setup, requests):
         if pending:
             flags = eng.admit_many(pending)
             pending = [r for r, ok in zip(pending, flags) if not ok]
-        kv_expected.append((float(eng.allocator.reserved * eng.block_size),
-                            float(eng.tokens_in_use)))
+        kv_expected.append((
+            float(eng.allocator.reserved * eng.block_size),
+            float(eng.tokens_in_use),
+            float(sum(-(-s.cache_len // 8) for s in eng.slots if s)),
+            float(4 * eng.n_bt)))
         for s in eng.step_chunk():
             done[s.rid] = s.tokens
     events = tr.to_chrome()["traceEvents"]
@@ -285,11 +289,14 @@ def test_engine_spans_and_kv_counter(setup, requests):
         assert a["args"]["rows"] == len(a["args"]["rids"])
 
     # one KV sample per chunk, inside its prep, equal to the engine's
-    # reservation and occupancy as the chunk began
+    # reservation and occupancy as the chunk began, and to the blocks the
+    # paged kernel streams against the tables' capacity (4 rows x 8)
     kv = [e for e in events if e["ph"] == "C" and e["name"] == "continuous.kv"]
-    assert [(e["args"]["reserved_tokens"], e["args"]["tokens_in_use"])
+    assert [tuple(e["args"][k] for k in ("reserved_tokens", "tokens_in_use",
+                                         "streamed_blocks", "table_blocks"))
             for e in kv] == kv_expected
-    assert all(r >= u > 0 for r, u in kv_expected)
+    assert all(r >= u > 0 for r, u, _, _ in kv_expected)
+    assert all(0 < n <= t == 32 for _, _, n, t in kv_expected)
     for sample, c in zip(kv, chunks):
         prep = _parts(spans, c, CHUNK_PARTS)[0]
         assert prep["ts"] <= sample["ts"] <= prep["ts"] + prep["dur"]

@@ -54,21 +54,28 @@ def _compiled_text(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_paged_decode_kernel_compiles(one_chip):
-    """Paged decode over the serving pool: 28 layers, 8 rows x 64 pages
-    of 16 tokens."""
-    L, bs, n_bt = 28, 16, 64
-    P = B * n_bt
+@pytest.mark.parametrize("rows,n_bt,g,L", [
+    (B, 64, G, 28),       # 8 rows x 64 pages of 16 tokens
+    (128, 32, 2, 28),     # qwen3-0.6b.table1
+    (64, 32, 4, 18),      # qwen3-8b-18l.table1
+    (32, 265, 2, 28),     # qwen3-0.6b.long_reasoning
+])
+def test_paged_decode_kernel_compiles(one_chip, rows, n_bt, g, L):
+    """Paged decode over the serving pool at each benchmark cell's shape:
+    one kernel, named as the trace reduction looks it up."""
+    bs = 16
+    P = rows * n_bt
     text = _compiled_text(
         lambda q, k, v, bt, pos: paged_decode_attention(
             q, k, v, bt, pos, layer=L - 1, interpret=False),
         one_chip,
-        ((B, NKV, G, HD), BF16),
+        ((rows, NKV, g, HD), BF16),
         ((L, P, NKV, bs, HD), BF16),
         ((L, P, NKV, bs, HD), BF16),
-        ((B, n_bt), jnp.int32),
-        ((B,), jnp.int32))
-    assert "tpu_custom_call" in text
+        ((rows, n_bt), jnp.int32),
+        ((rows,), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+    assert "%paged_decode_attention" in text
 
 
 def test_slot_decode_kernel_compiles(one_chip):
